@@ -227,6 +227,9 @@ def main(argv=None) -> int:
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # fake CPU devices for structure checks: the child must never take
+    # the accelerator, which the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
         + env.get("PYTHONPATH", "")

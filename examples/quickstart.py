@@ -6,9 +6,11 @@
 import numpy as np
 
 from repro.core import Planner, fft_conv, run_variant, VARIANTS
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     rng = np.random.default_rng(0)
     x = rng.standard_normal((256, 512)).astype(np.float32)
     ref = np.fft.rfft2(x)

@@ -1,6 +1,6 @@
 """The paper's contribution: planned, variant-swappable, distributed FFT."""
 
-from . import algo, api, comm, compat, dfft, fftconv, plan, variants, wisdom
+from . import algo, api, comm, dfft, fftconv, plan, variants, wisdom
 from .algo import fft, fft2, ifft, irfft, rfft, rfft2, to_complex, to_pair
 from .api import (NdPlan, execute_nd, execute_nd_inverse, fftn, ifftn,
                   irfftn, plan_nd, rfftn)
@@ -16,12 +16,13 @@ from .comm import (COMM_BACKENDS, AgasBackend, CollectiveBackend, CommBackend,
 from .dfft import (collect, distribute, fft2_slab, fft3_pencil, ifft2_slab,
                    ifft3_pencil, irfft3_pencil, rfft3_pencil)
 from .fftconv import factor_split, fft_conv, fft_conv_seq_sharded
-from .plan import CPU_LOCAL, TPU_V5E, Plan, Planner, execute, execute_inverse
+from .plan import (CPU_LOCAL, TPU_V5E, Plan, Planner, execute,
+                   execute_inverse, hardware_for)
 from .variants import VARIANTS, run_variant
 from .wisdom import WisdomStore
 
 __all__ = [
-    "algo", "api", "comm", "compat", "dfft", "fftconv", "plan", "variants",
+    "algo", "api", "comm", "dfft", "fftconv", "plan", "variants",
     "wisdom",
     "fft", "ifft", "rfft", "irfft", "fft2", "rfft2",
     "to_pair", "to_complex",
@@ -43,5 +44,6 @@ __all__ = [
     "distribute", "collect",
     "factor_split", "fft_conv", "fft_conv_seq_sharded",
     "Plan", "Planner", "execute", "execute_inverse", "TPU_V5E", "CPU_LOCAL",
+    "hardware_for",
     "VARIANTS", "run_variant",
 ]

@@ -484,18 +484,20 @@ def _effective_chunks(chunks: int, w: int) -> int:
 
 def _time_callable(fn, args, reps: int) -> float:
     """Compile + warmup, then wall-time ``reps`` executions (median-free
-    mean, like ``Planner._measure``).  Returns +inf on any failure so a
-    broken candidate loses rather than crashes the sweep."""
+    mean, like ``Planner._measure``).  A candidate that raises
+    ``NotImplementedError`` is unsupported here and returns +inf, so it
+    loses the sweep; any other failure (out of memory, a refused compile)
+    propagates rather than becoming a verdict."""
     try:
         out = fn(*args)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        dt = (time.perf_counter() - t0) / reps
-    except Exception:
+    except NotImplementedError:
         return float("inf")
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    dt = (time.perf_counter() - t0) / reps
     MEASURE_STATS["timed"] += 1
     return dt
 
@@ -510,7 +512,6 @@ def _time_exchange(backend: CommBackend, mesh, axis_name: str,
     ``axis_name`` (every device holds ``local_shape``), redistributed to
     ``split``-sharded — the same collective the transform will emit.
     """
-    from .compat import shard_map
     ndim = len(local_shape)
     global_shape = list(local_shape)
     global_shape[concat] *= p
@@ -528,8 +529,8 @@ def _time_exchange(backend: CommBackend, mesh, axis_name: str,
         return backend.exchange((a, b), axis_name, split=split,
                                 concat=concat, p=p)
 
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=(pin, pin),
-                           out_specs=(pout, pout)))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(pin, pin),
+                               out_specs=(pout, pout)))
     return _time_callable(fn, probe, reps)
 
 
@@ -538,7 +539,6 @@ def _time_gather(backend: CommBackend, mesh, axis_name: str, nb: int,
     """Time one compressed-payload gather (int8 values + bf16 scales) plus
     the dequantize-sum it must hide behind — the collective
     :func:`repro.optim.compress.compressed_psum` issues."""
-    from .compat import shard_map
     rng = np.random.default_rng(0)
     q = jax.device_put(
         rng.integers(-127, 128, (p * nb, block)).astype(np.int8),
@@ -552,7 +552,7 @@ def _time_gather(backend: CommBackend, mesh, axis_name: str, nb: int,
         return jnp.sum(qg.astype(jnp.float32) * sg.astype(jnp.float32),
                        axis=0)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(PartitionSpec(axis_name, None),) * 2,
         out_specs=PartitionSpec(axis_name, None)))
@@ -597,12 +597,12 @@ def _candidate_specs(width: int, chunk_candidates: Sequence[int],
 
 
 def _run_sweep(specs: Sequence[str], timer) -> Tuple[str, Dict[str, float]]:
-    """Time every candidate and keep the fastest; failed candidates (inf)
-    lose, and an all-failed sweep falls back to the collective."""
+    """Time every candidate and keep the fastest; unsupported candidates
+    (inf) lose, and a sweep with no supported candidate is an error."""
     timings = {spec: timer(spec) for spec in specs}
     finite = {k: v for k, v in timings.items() if v != float("inf")}
     if not finite:
-        return "collective", timings
+        raise RuntimeError(f"no supported exchange candidate among {specs}")
     return min(finite, key=finite.get), timings
 
 

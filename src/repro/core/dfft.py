@@ -25,7 +25,7 @@ One shared pad-and-crop layer serves every path:
   and re-padding after, so the padded band stays exactly zero through every
   exchange and is cropped once at the end (``NdPlan.crop``);
 * **leading batch dims** ride through every executor via the batched
-  shard_map spec helper (:func:`repro.core.compat.batched_spec`) shared
+  shard_map spec helper (:func:`batched_spec`) shared
   with :func:`repro.core.fftconv.fft_conv_seq_sharded`.
 
 Algorithm (slab, 2D r2c, row-major N x M, P devices; paper's five steps):
@@ -50,6 +50,8 @@ should go through :func:`repro.core.api.plan_nd` and the ``fftn`` family.
 
 from __future__ import annotations
 
+import functools
+import math
 import warnings
 from typing import Optional, Tuple
 
@@ -63,7 +65,6 @@ from .comm import (COMM_BACKENDS, CommBackend, CommSpec, get_backend,
                    measure_comm_pencil, measure_comm_slab, pad_to,
                    padded_half, plan_comm, plan_comm_pencil,
                    resolve_axis_backends)
-from .compat import batched_spec, shard_map
 from .plan import Plan, Planner, execute, execute_inverse
 
 Complex = algo.Complex
@@ -96,12 +97,32 @@ def _pad_axis(c: Complex, axis: int, target: int) -> Complex:
     return jnp.pad(c[0], widths), jnp.pad(c[1], widths)
 
 
+def crop_to(a: jax.Array, axis: int, n: int) -> jax.Array:
+    """Crop one axis of an array to its first ``n`` entries.
+
+    A mesh with ``Explicit`` axes (``jax.make_mesh``'s default) keeps every
+    sharded axis evenly divided, so an axis whose mesh axes do not divide
+    ``n`` is gathered over them first; on ``Auto`` meshes and inside
+    ``shard_map`` blocks the type carries no such axis and this is a plain
+    slice."""
+    axis %= a.ndim
+    if a.shape[axis] == n:
+        return a
+    sharding = jax.typeof(a).sharding
+    spec = tuple(sharding.spec) + (None,) * (a.ndim - len(sharding.spec))
+    names = spec[axis]
+    if names is not None:
+        names = names if isinstance(names, tuple) else (names,)
+        if n % math.prod(sharding.mesh.shape[m] for m in names):
+            spec = spec[:axis] + (None,) + spec[axis + 1:]
+            a = jax.sharding.reshard(
+                a, jax.sharding.NamedSharding(sharding.mesh, P(*spec)))
+    return jax.lax.slice_in_dim(a, 0, n, axis=axis)
+
+
 def _crop_axis(c: Complex, axis: int, n: int) -> Complex:
     """Crop one axis of a pair back to its true length ``n``."""
-    if c[0].shape[axis] == n:
-        return c
-    return (jax.lax.slice_in_dim(c[0], 0, n, axis=axis),
-            jax.lax.slice_in_dim(c[1], 0, n, axis=axis))
+    return crop_to(c[0], axis, n), crop_to(c[1], axis, n)
 
 
 def _fft_axis(plan: Plan, c: Complex, axis: int, inverse: bool = False
@@ -144,19 +165,38 @@ def rows_irfft(planner: Planner, c: Complex, n: int) -> jax.Array:
 
 def _warm_rows_plan(planner: Planner, n: int, inverse: bool = False) -> None:
     """Pre-plan the 1D stage :func:`rows_rfft` / :func:`rows_irfft` will
-    request, OUTSIDE any traced function — their trace-time lookups then hit
-    the planner's wisdom cache without triggering a wisdom write."""
+    request, outside the ``shard_map`` body — its trace-time lookups then
+    hit the planner's wisdom cache without triggering a wisdom write."""
     if n % 2 == 0:
         planner.plan(n, kind="c2r" if inverse else "r2c")
     else:
         planner.plan(n, kind="c2c")
 
 
-def _local_rows_rfft(x: jax.Array, plan: Plan, mh_pad: int) -> Complex:
-    """r2c FFT along the last axis + zero-pad to the collective-divisible
-    width (works for any number of leading batch axes)."""
-    re, im = execute(plan, x)
-    return _pad_axis((re, im), -1, mh_pad)
+def _compiled(*static_names: str):
+    """Run an executor as one compiled program per (plan, mesh, planner,
+    options, input shapes).
+
+    Called eagerly, ``shard_map`` runs its body primitive by primitive and
+    compiles each primitive anew on every call; the constants made in the
+    body (DFT matrices, twiddles) are concrete single-device arrays, which
+    a mesh with ``Explicit`` axes refuses.  Under ``jit`` the body is
+    traced once and compiled once, and a call from inside a caller's own
+    ``jit`` is inlined there."""
+    return functools.partial(jax.jit, static_argnums=(0, 2, 3),
+                             static_argnames=("chunks",) + static_names)
+
+
+def batched_spec(spec, batch_ndim: int) -> P:
+    """Prepend ``batch_ndim`` replicated (None) dims to a PartitionSpec.
+
+    The one batching convention for every shard_map'd transform: leading
+    batch axes are never sharded by the FFT layer, so a spec written for the
+    unbatched layout extends to any batch rank.  Shared by the executors
+    here and :func:`repro.core.fftconv.fft_conv_seq_sharded`."""
+    if batch_ndim <= 0:
+        return spec
+    return P(*((None,) * batch_ndim + tuple(spec)))
 
 
 def _slab_backend(nd, chunks: int) -> CommBackend:
@@ -198,6 +238,7 @@ def _pencil_spectrum_spec(axs, k: int, d: int) -> P:
 # spectrum.
 
 
+@_compiled("keep_transposed", "permuted_cols")
 def execute_slab(nd, x, mesh: jax.sharding.Mesh, planner: Planner, *,
                  chunks: int = 4, keep_transposed: bool = False,
                  permuted_cols: bool = False):
@@ -279,10 +320,11 @@ def execute_slab(nd, x, mesh: jax.sharding.Mesh, planner: Planner, *,
         spec_out = batched_spec(P(ax, *(None,) * (d - 1)), bnd)
     in_specs = (spec_in, spec_in) if pair_in else (spec_in,)
     args = x if pair_in else (x,)
-    return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=(spec_out, spec_out))(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=(spec_out, spec_out))(*args)
 
 
+@_compiled("from_transposed", "permuted_cols")
 def execute_slab_inverse(nd, c: Complex, mesh: jax.sharding.Mesh,
                          planner: Planner, *, chunks: int = 4,
                          from_transposed: bool = False,
@@ -355,8 +397,8 @@ def execute_slab_inverse(nd, c: Complex, mesh: jax.sharding.Mesh,
     else:
         spec_in = spec_std
     out_specs = spec_std if nd.kind == "r2c" else (spec_std, spec_std)
-    return shard_map(local, mesh=mesh, in_specs=(spec_in, spec_in),
-                     out_specs=out_specs)(c[0], c[1])
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec_in, spec_in),
+                         out_specs=out_specs)(c[0], c[1])
 
 
 # ---------------------------------------------------------------------------
@@ -385,6 +427,7 @@ def execute_slab_inverse(nd, c: Complex, mesh: jax.sharding.Mesh,
 # backend both ways.
 
 
+@_compiled()
 def execute_pencil(nd, x, mesh: jax.sharding.Mesh, planner: Planner, *,
                    chunks: int = 4):
     """Forward pencil transform of an :class:`~repro.core.api.NdPlan`
@@ -438,10 +481,11 @@ def execute_pencil(nd, x, mesh: jax.sharding.Mesh, planner: Planner, *,
     spec_out = batched_spec(_pencil_spectrum_spec(axs, k, d), bnd)
     in_specs = (spec_in, spec_in) if pair_in else (spec_in,)
     args = x if pair_in else (x,)
-    return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=(spec_out, spec_out))(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=(spec_out, spec_out))(*args)
 
 
+@_compiled()
 def execute_pencil_inverse(nd, c: Complex, mesh: jax.sharding.Mesh,
                            planner: Planner, *, chunks: int = 4):
     """Inverse pencil transform: PADDED spectrum pair in (zero padded
@@ -482,8 +526,8 @@ def execute_pencil_inverse(nd, c: Complex, mesh: jax.sharding.Mesh,
     spec_in = batched_spec(_pencil_spectrum_spec(axs, k, d), bnd)
     spec_out = batched_spec(P(*axs, *(None,) * (d - k)), bnd)
     out_specs = spec_out if nd.kind == "r2c" else (spec_out, spec_out)
-    return shard_map(local, mesh=mesh, in_specs=(spec_in, spec_in),
-                     out_specs=out_specs)(c[0], c[1])
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec_in, spec_in),
+                         out_specs=out_specs)(c[0], c[1])
 
 
 # ---------------------------------------------------------------------------
@@ -529,6 +573,7 @@ def _factor1d_twiddle_block(n1: int, n2: int, axis_name: str, p: int,
     return jnp.cos(ang), jnp.sin(ang)
 
 
+@_compiled()
 def execute_factor1d(nd, x, mesh: jax.sharding.Mesh, planner: Planner, *,
                      chunks: int = 4) -> Complex:
     """Forward distributed 1D c2c transform of an
@@ -565,10 +610,11 @@ def execute_factor1d(nd, x, mesh: jax.sharding.Mesh, planner: Planner, *,
         return z[0].reshape(flat), z[1].reshape(flat)
 
     spec = batched_spec(P(ax), bnd)
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec))(x[0], x[1])
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec))(x[0], x[1])
 
 
+@_compiled()
 def execute_factor1d_inverse(nd, c: Complex, mesh: jax.sharding.Mesh,
                              planner: Planner, *,
                              chunks: int = 4) -> Complex:
@@ -603,8 +649,8 @@ def execute_factor1d_inverse(nd, c: Complex, mesh: jax.sharding.Mesh,
         return z[0].reshape(flat), z[1].reshape(flat)
 
     spec = batched_spec(P(ax), bnd)
-    return shard_map(local, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec))(c[0], c[1])
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec))(c[0], c[1])
 
 
 # ---------------------------------------------------------------------------

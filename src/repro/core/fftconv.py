@@ -28,6 +28,7 @@ The distributed 1D FFT views the length-L signal as an (N1, N2) matrix
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -38,7 +39,7 @@ from jax.sharding import PartitionSpec as P
 from . import algo
 from .comm import (CommBackend, CommSpec, get_backend, measure_comm_conv,
                    plan_comm_conv)
-from .compat import batched_spec, shard_map
+from .dfft import batched_spec
 from .plan import Planner
 
 Complex = algo.Complex
@@ -244,6 +245,20 @@ def fft_conv_seq_sharded(u: jax.Array, k: jax.Array,
     elif comm == "measure":
         comm = measure_comm_conv(b, d, n1, n2, mesh, axis,
                                  wisdom=planner.wisdom)
+    return _conv_seq_sharded(u, k, mesh, axis, planner, comm, chunks, n1, n2)
+
+
+@functools.partial(jax.jit, static_argnums=tuple(range(2, 9)))
+def _conv_seq_sharded(u: jax.Array, k: jax.Array, mesh, axis: str,
+                      planner: Planner, comm: CommSpec, chunks: int,
+                      n1: int, n2: int) -> jax.Array:
+    """The compiled body of :func:`fft_conv_seq_sharded`: one program per
+    mesh, resolved exchange spec and shapes (an eager ``shard_map`` would
+    compile every primitive of its body on every call; see
+    :func:`repro.core.dfft._compiled`)."""
+    slen = u.shape[1]
+    p = mesh.shape[axis]
+    nf = n1 * n2
     backend = get_backend(comm, chunks=chunks)
 
     # global zero-padding to the FFT length (outside shard_map: the tail
@@ -263,7 +278,7 @@ def fft_conv_seq_sharded(u: jax.Array, k: jax.Array,
     # the (B, L, D) activations and (D, L) filters share the batched-spec
     # convention of the dfft executors: one leading replicated batch dim
     # prepended to the sharded-sequence spec
-    y = shard_map(
+    y = jax.shard_map(
         local, mesh=mesh,
         in_specs=(batched_spec(P(axis, None), 1), batched_spec(P(axis), 1)),
         out_specs=batched_spec(P(axis, None), 1),

@@ -53,9 +53,25 @@ class HardwareSpec:
 
 TPU_V5E = HardwareSpec("tpu_v5e", flops=197e12 / 2, hbm_bw=819e9, link_bw=50e9,
                        matmul_dim=128, vmem_bytes=128 * 2 ** 20)
-# f32 matmul on v5e runs at half bf16 rate; FFT twiddles/DFT matrices are f32.
+# 197 TFLOP/s bf16 and 819 GB/s HBM are the published v5e peaks (Google Cloud
+# "TPU v5e").  The DFT matmuls are f32 at Precision.HIGHEST (several bf16
+# passes), so flops=bf16/2 and link_bw are guesses until a chip run
+# measures them.
 CPU_LOCAL = HardwareSpec("cpu_local", flops=5e9, hbm_bw=20e9, link_bw=1e9,
                          matmul_dim=8, vmem_bytes=32 * 2 ** 20)
+
+#: roofline constants keyed by ``jax.Device.device_kind``
+HARDWARE_BY_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` of a device kind.  A kind that is not in
+    :data:`HARDWARE_BY_KIND` is an error, never another chip's peaks."""
+    try:
+        return HARDWARE_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(f"no HardwareSpec for device kind {device_kind!r} "
+                         f"(known: {sorted(HARDWARE_BY_KIND)})") from None
 
 
 # ---------------------------------------------------------------------------
@@ -240,20 +256,22 @@ class Planner:
         else:
             probe = jnp.ones((batch, n), jnp.float32)
         for p in cands:
+            fn = jax.jit(lambda a, _p=p: execute(_p, a))
             try:
-                fn = jax.jit(lambda a, _p=p: execute(_p, a))
                 out = fn(probe)
-                jax.block_until_ready(out)
-                reps, t0 = 3, time.perf_counter()
-                for _ in range(reps):
-                    out = fn(probe)
-                jax.block_until_ready(out)
-                dt = (time.perf_counter() - t0) / reps
-            except Exception:
+            except NotImplementedError:     # unsupported here: skip it
                 continue
+            jax.block_until_ready(out)
+            reps, t0 = 3, time.perf_counter()
+            for _ in range(reps):
+                out = fn(probe)
+            jax.block_until_ready(out)
+            dt = (time.perf_counter() - t0) / reps
             if dt < best_t:
                 best, best_t = p, dt
-        assert best is not None
+        if best is None:
+            raise RuntimeError(f"no supported plan candidate for n={n} "
+                               f"({kind})")
         return dataclasses.replace(best, measured_cost=best_t)
 
 

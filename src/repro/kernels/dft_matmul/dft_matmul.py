@@ -14,23 +14,27 @@ Layout notes (TPU):
   * n1 sits in sublanes; the step-1 contraction is expressed with
     dot_general over the middle axis so Mosaic keeps the lane layout.
   * DFT matrices / twiddles are f32 VMEM residents shared by all rows of the
-    block; f32 accumulate via preferred_element_type.
+    block; the matmuls run at Precision.HIGHEST with f32 accumulation (the
+    default precision is one bf16 pass on a TPU).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..interpret import resolve_interpret
 
 
 def _cdot(ar, ai, br, bi, karatsuba: bool):
     """Complex contraction: (..., k) x (k, m) -> (..., m)."""
     dn = (((ar.ndim - 1,), (0,)), ((), ()))
     mm = functools.partial(jax.lax.dot_general, dimension_numbers=dn,
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
     if karatsuba:
         p1 = mm(ar, br)
@@ -77,11 +81,12 @@ def fft_four_step_pallas(x: Tuple[jax.Array, jax.Array],
                          factors: Tuple[int, int],
                          *, karatsuba: bool = False, permuted: bool = False,
                          block_rows: int = 8,
-                         interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                         interpret: Optional[bool] = None
+                         ) -> Tuple[jax.Array, jax.Array]:
     """Batched c2c FFT along the last axis; x = (re, im), shape (..., n).
 
-    ``interpret=True`` runs the kernel body on CPU (this container); on real
-    TPU pass interpret=False.
+    ``interpret=None`` interprets the kernel body on the CPU backend and
+    compiles it on a TPU (:func:`repro.kernels.interpret.resolve_interpret`).
     """
     from repro.core import algo
 
@@ -120,6 +125,6 @@ def fft_four_step_pallas(x: Tuple[jax.Array, jax.Array],
                   const((n2, n2)), const((n2, n2))],
         out_specs=[data_spec, data_spec],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xr2, xi2, w1[0], w1[1], tw[0], tw[1], w2[0], w2[1])
     return orr.reshape(*batch_shape, n), oii.reshape(*batch_shape, n)

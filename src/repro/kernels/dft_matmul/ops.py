@@ -1,26 +1,17 @@
 """Jitted public wrapper for the dft_matmul kernel.
 
-``interpret`` defaults to True because this container is CPU-only; real-TPU
-deployments flip REPRO_PALLAS_INTERPRET=0 (the launcher does this when
-jax.default_backend() == 'tpu').
+The kernel runs compiled on a TPU and in Pallas interpret mode on the CPU
+backend (see :func:`repro.kernels.interpret.resolve_interpret`).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
 
 from .dft_matmul import fft_four_step_pallas
-
-
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("factors", "karatsuba", "permuted",
@@ -29,5 +20,4 @@ def fft_four_step(x: Tuple[jax.Array, jax.Array], factors: Tuple[int, int],
                   *, karatsuba: bool = False, permuted: bool = False,
                   block_rows: int = 8) -> Tuple[jax.Array, jax.Array]:
     return fft_four_step_pallas(x, tuple(factors), karatsuba=karatsuba,
-                                permuted=permuted, block_rows=block_rows,
-                                interpret=_interpret_default())
+                                permuted=permuted, block_rows=block_rows)

@@ -21,16 +21,19 @@ the grid.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..interpret import resolve_interpret
+
 
 def _cdot(ar, ai, br, bi):
     dn = (((ar.ndim - 1,), (0,)), ((), ()))
     mm = functools.partial(jax.lax.dot_general, dimension_numbers=dn,
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
     return mm(ar, br) - mm(ai, bi), mm(ar, bi) + mm(ai, br)
 
@@ -79,7 +82,7 @@ def _fftconv_kernel(x_ref, hr_ref, hi_ref,
 
 def fftconv_fused_pallas(x: jax.Array, h_spec: Tuple[jax.Array, jax.Array],
                          factors: Tuple[int, int], *, block_rows: int = 8,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """Circular convolution of real rows x (B, nf) with a filter given as a
     PERMUTED-order spectrum pair (nf,).  Returns real (B, nf)."""
     from repro.core import algo
@@ -114,7 +117,7 @@ def fftconv_fused_pallas(x: jax.Array, h_spec: Tuple[jax.Array, jax.Array],
                   c2((n2, n2)), c2((n2, n2))],
         out_specs=data,
         out_shape=jax.ShapeDtypeStruct((b, nf), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x.astype(jnp.float32), h_spec[0], h_spec[1],
       w1[0], w1[1], tw[0], tw[1], w2[0], w2[1],
       v1[0], v1[1], vt[0], vt[1], v2[0], v2[1])

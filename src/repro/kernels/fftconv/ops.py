@@ -3,7 +3,6 @@
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -11,17 +10,9 @@ import jax
 from .fftconv import fftconv_fused_pallas, filter_spectrum_permuted
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("factors", "block_rows"))
 def fftconv_fused(x: jax.Array, h: jax.Array, factors: Tuple[int, int],
                   *, block_rows: int = 8) -> jax.Array:
     """y[b] = circular_conv(x[b], h), fused in VMEM. x (B, nf); h (nf,)."""
     h_spec = filter_spectrum_permuted(h, factors)
-    return fftconv_fused_pallas(x, h_spec, factors, block_rows=block_rows,
-                                interpret=_interpret_default())
+    return fftconv_fused_pallas(x, h_spec, factors, block_rows=block_rows)

@@ -3,20 +3,12 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
 from .transpose import transpose_tiled
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block",))
 def transpose(x: jax.Array, *, block: int = 128) -> jax.Array:
-    return transpose_tiled(x, block=block, interpret=_interpret_default())
+    return transpose_tiled(x, block=block)

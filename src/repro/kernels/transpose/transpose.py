@@ -13,11 +13,13 @@ slab rearrange.
 
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..interpret import resolve_interpret
 
 
 def _transpose_kernel(x_ref, o_ref):
@@ -25,7 +27,7 @@ def _transpose_kernel(x_ref, o_ref):
 
 
 def transpose_tiled(x: jax.Array, *, block: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """(..., n, m) -> (..., m, n). Batch dims are grid-mapped."""
     *batch, n, m = x.shape
     b = 1
@@ -46,6 +48,6 @@ def transpose_tiled(x: jax.Array, *, block: int = 128,
         in_specs=[pl.BlockSpec((1, bi, bj), lambda k, j, i: (k, i, j))],
         out_specs=pl.BlockSpec((1, bj, bi), lambda k, j, i: (k, j, i)),
         out_shape=jax.ShapeDtypeStruct((b, m, n), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x3)
     return out.reshape(*batch, m, n)

@@ -3,21 +3,12 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
 from .twiddle import complex_multiply_pallas
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block",))
 def complex_multiply(a, b, *, block: int = 1024):
-    return complex_multiply_pallas(a, b, block=block,
-                                   interpret=_interpret_default())
+    return complex_multiply_pallas(a, b, block=block)

@@ -8,9 +8,13 @@ halves HBM traffic versus two separate jnp multiplies.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..interpret import resolve_interpret
 
 
 def _cmul_kernel(ar_ref, ai_ref, br_ref, bi_ref, or_ref, oi_ref):
@@ -20,7 +24,7 @@ def _cmul_kernel(ar_ref, ai_ref, br_ref, bi_ref, or_ref, oi_ref):
     oi_ref[...] = ar * bi + ai * br
 
 
-def complex_multiply_pallas(a, b, *, block: int = 1024, interpret: bool = True):
+def complex_multiply_pallas(a, b, *, block: int = 1024, interpret: Optional[bool] = None):
     """Elementwise (re, im) * (re, im). b broadcasts over leading dims of a."""
     ar, ai = a
     br, bi = b
@@ -40,6 +44,6 @@ def complex_multiply_pallas(a, b, *, block: int = 1024, interpret: bool = True):
         in_specs=[spec] * 4,
         out_specs=[spec] * 2,
         out_shape=[jax.ShapeDtypeStruct((flat,), ar.dtype)] * 2,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ar.reshape(flat), ai.reshape(flat), br.reshape(flat), bi.reshape(flat))
     return orr.reshape(shape), oi.reshape(shape)

@@ -1,12 +1,15 @@
 import os
-os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_DRYRUN_XLA_FLAGS")
-                           or "--xla_force_host_platform_device_count=512")
+if __name__ == "__main__":      # run as a program: fake 512 host devices
+    os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_DRYRUN_XLA_FLAGS")
+                               or "--xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 extract the roofline terms from the compiled artifact.
 
-The two lines above run before ANY other import (jax locks the device count
-on first init).  Everything below is ordinary code.
+Run as a program, the lines above set the device count before ANY other
+import (jax locks it on first init); imported as a module (for
+``parse_collectives`` and friends) it leaves ``XLA_FLAGS`` alone.
+Everything below is ordinary code.
 
 Usage:
   python -m repro.launch.dryrun --arch granite-8b --shape train_4k --mesh single

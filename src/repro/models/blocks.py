@@ -14,8 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import scan_carry
-
 from .config import ArchConfig
 from .params import ParamMeta, shard_act
 
@@ -186,9 +184,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     acc0 = jnp.zeros((b, sq, kvh, g, hd), jnp.float32)
     m0 = jnp.full((b, sq, kvh, g), -1e30, jnp.float32)
     l0 = jnp.zeros((b, sq, kvh, g), jnp.float32)
-    # scan_carry: plain lax.scan on modern JAX; unrolled on JAX 0.4.x so the
-    # kv loop survives inside partial-manual shard_map regions (GPipe stages)
-    (acc, _, l_run), _ = scan_carry(
+    (acc, _, l_run), _ = jax.lax.scan(
         step, (acc0, m0, l0), (kb, vb, jnp.arange(nkv)))
     out = acc / jnp.maximum(l_run[..., None], 1e-30)
     return out.reshape(b, sq, h, hd).astype(q.dtype)

@@ -14,28 +14,20 @@ trains.  Stage-sliced layer parameters arrive sharded over the pod axis.
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Tuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import legacy_partial_manual, pvary, ring_shift
-
 
 def pipeline_stages(stage_fn: Callable[[Any, jax.Array], jax.Array],
-                    stage_params: Any, x_mb: jax.Array, axis: str,
-                    me: jax.Array | None = None):
+                    stage_params: Any, x_mb: jax.Array, axis: str):
     """Like :func:`pipeline_forward` but WITHOUT the final broadcast: returns
     (outs, my_stage_index, num_stages) where ``outs`` holds valid microbatch
     outputs only on the last stage (zeros elsewhere).  Callers that reduce to
     a scalar (the LM loss) mask by stage and psum — no activation ever
-    crosses the pod axis outside the ppermute ring.
-
-    ``me`` optionally supplies the caller's stage index as data (an iota
-    sharded over ``axis``) — REQUIRED under partial-manual shard_map on JAX
-    0.4.x, where ``axis_index`` cannot lower (see repro.core.compat)."""
-    return _pipeline_impl(stage_fn, stage_params, x_mb, axis, me)
+    crosses the pod axis outside the ppermute ring."""
+    return _pipeline_impl(stage_fn, stage_params, x_mb, axis)
 
 
 def pipeline_forward(stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -58,32 +50,12 @@ def pipeline_forward(stage_fn: Callable[[Any, jax.Array], jax.Array],
     return outs_all[s - 1]
 
 
-def _pipeline_impl(stage_fn, stage_params, x_mb, axis: str, me=None):
+def _pipeline_impl(stage_fn, stage_params, x_mb, axis: str):
     s = jax.lax.psum(1, axis)                                   # stage count
-    if me is None:          # full-manual meshes: axis_index lowers everywhere
-        me = jax.lax.axis_index(axis)
+    me = jax.lax.axis_index(axis)
     m = x_mb.shape[0]
     ticks = m + s - 1
-    # The injected microbatch is only CONSUMED on stage 0, where t - me == t,
-    # so the schedule index stays axis-invariant — required on JAX 0.4.x,
-    # whose partitioner cannot lower a manual-axis-varying gather of a
-    # region input.
-
-    if legacy_partial_manual():
-        # 0.4.x partial-manual region: GSPMD cannot partition a while-loop
-        # whose body mixes manual-subgroup collectives with gathers of
-        # region inputs (hlo_sharding_util CHECK failure), so the tick loop
-        # unrolls — ticks is static and small (M + S - 1).
-        buf = pvary(jnp.zeros(x_mb.shape[1:], x_mb.dtype), (axis,))
-        ys = []
-        for t in range(ticks):
-            inp = jnp.where(me == 0, x_mb[min(t, m - 1)], buf)
-            ys.append(stage_fn(stage_params, inp))
-            buf = ring_shift(ys[-1], axis, me, s)
-        # tick t completes microbatch t - (s-1) on the last stage
-        outs = jnp.stack(ys[s - 1:s - 1 + m])
-        outs = jnp.where(me == s - 1, outs, jnp.zeros_like(outs))
-        return outs, me, s
+    ring = [(i, (i + 1) % s) for i in range(s)]     # stage i -> stage i+1
 
     def tick(carry, t):
         buf, outs = carry                                       # buf: (mb, ...)
@@ -96,12 +68,12 @@ def _pipeline_impl(stage_fn, stage_params, x_mb, axis: str, me=None):
         store = jnp.logical_and(me == s - 1, t >= s - 1)
         upd = jax.lax.dynamic_update_index_in_dim(outs, out, done_idx, 0)
         outs = jnp.where(store, upd, outs)
-        buf = ring_shift(out, axis, me, s)
+        buf = jax.lax.ppermute(out, axis, ring)
         return (buf, outs), None
 
     out_shape = jax.eval_shape(stage_fn, stage_params, x_mb[0])
-    buf0 = pvary(jnp.zeros(x_mb.shape[1:], x_mb.dtype), (axis,))
-    outs0 = pvary(
+    buf0 = jax.lax.pvary(jnp.zeros(x_mb.shape[1:], x_mb.dtype), (axis,))
+    outs0 = jax.lax.pvary(
         jnp.zeros((m,) + out_shape.shape, out_shape.dtype), (axis,))
     (_, outs), _ = jax.lax.scan(tick, (buf0, outs0), jnp.arange(ticks))
     return outs, me, s

@@ -15,14 +15,12 @@ activation broadcast across pods at all.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.compat import partial_manual_region, scan_carry, shard_map
 from repro.models import blocks, lm
 from repro.models.config import ArchConfig
 from repro.models.params import shard_act, sharding_rules
@@ -68,15 +66,12 @@ def pipelined_loss_fn(params: Dict[str, Any], cfg: ArchConfig,
             if cfg.remat:
                 body = jax.checkpoint(
                     layer, policy=jax.checkpoint_policies.nothing_saveable)
-            out, _ = scan_carry(body, xin, layers_local)
+            out, _ = jax.lax.scan(body, xin, layers_local)
             return out
 
-        def pod_body(layers_stage, stage_id, xmb, labmb, norm_p, head_):
-            # stage index arrives as DATA (iota sharded over "pod"):
-            # axis_index cannot lower under partial-manual shard_map on
-            # JAX 0.4.x (see repro.core.compat)
+        def pod_body(layers_stage, xmb, labmb, norm_p, head_):
             outs, me, stages = pipeline_stages(stage, layers_stage, xmb,
-                                               "pod", me=stage_id[0])
+                                               "pod")
             # head + loss on the LAST stage only; psum the masked scalar
             y = blocks.apply_norm(norm_p, cfg, outs.reshape(bsz, s,
                                                             cfg.d_model))
@@ -94,19 +89,15 @@ def pipelined_loss_fn(params: Dict[str, Any], cfg: ArchConfig,
             nll = jnp.where(me == stages - 1, nll, 0.0)
             return jax.lax.psum(nll, "pod")
 
-        stage_ids = jnp.arange(mesh.shape["pod"], dtype=jnp.int32)
-        # partial_manual_region: "data"/"model" stay auto inside this
-        # shard_map, so on JAX 0.4.x the pipeline ring / inner loops must
-        # take their partitioner-safe fallbacks (see repro.core.compat)
-        with partial_manual_region():
-            nll = shard_map(
-                pod_body, mesh=mesh,
-                in_specs=(P("pod"), P("pod"), P(None, None, None, None),
-                          P(None, None, None), P(), P(None, None)),
-                out_specs=P(),
-                axis_names={"pod"}, check_vma=False,
-            )(params["segments"][0]["layers"], stage_ids, x_mb, lab_mb,
-              params["final_norm"], head)
+        # manual over "pod" only: "data"/"model" stay auto inside
+        nll = jax.shard_map(
+            pod_body, mesh=mesh,
+            in_specs=(P("pod"), P(None, None, None, None),
+                      P(None, None, None), P(), P(None, None)),
+            out_specs=P(),
+            axis_names={"pod"}, check_vma=False,
+        )(params["segments"][0]["layers"], x_mb, lab_mb,
+          params["final_norm"], head)
     return nll, {"nll": nll, "aux": jnp.zeros((), jnp.float32)}
 
 

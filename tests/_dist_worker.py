@@ -21,10 +21,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.core import api      # noqa: E402
 from repro.core import comm as comm_mod             # noqa: E402
 from repro.core import dfft, fftconv, plan          # noqa: E402
+from repro.launch.mesh import make_gspmd_mesh       # noqa: E402
 
 # the *_slab/*_pencil checks below exercise the deprecated shims on purpose
 warnings.filterwarnings("ignore", category=DeprecationWarning)
-from repro.core.compat import shard_map             # noqa: E402
 from repro.models import lm                         # noqa: E402
 from repro.optim import choose_psum_comm, compressed_psum   # noqa: E402
 from repro.parallel import pipeline_forward         # noqa: E402
@@ -158,7 +158,7 @@ def check_compressed_psum():
             out, err = compressed_psum(x[0], "pod", comm=_c)
             return out[None], err[None]
 
-        out, err = jax.jit(shard_map(
+        out, err = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("pod", None),
             out_specs=(P("pod", None), P("pod", None))))(xs)
         got = np.asarray(out)[0]
@@ -503,7 +503,7 @@ def check_pipeline_forward():
     def run(w_all, xin):
         return pipeline_forward(stage, w_all, xin, "pod")
 
-    y = jax.jit(shard_map(
+    y = jax.jit(jax.shard_map(
         run, mesh=mesh, in_specs=(P("pod", None, None), P(None, None, None)),
         out_specs=P(None, None, None), check_vma=False))(w, x)
     # reference: sequential stages
@@ -515,7 +515,7 @@ def check_pipeline_forward():
 
     # differentiability (GPipe backward through ppermute)
     def loss(w_all):
-        return jnp.sum(shard_map(
+        return jnp.sum(jax.shard_map(
             run, mesh=mesh, in_specs=(P("pod", None, None),
                                       P(None, None, None)),
             out_specs=P(None, None, None), check_vma=False)(w_all, x) ** 2)
@@ -539,7 +539,7 @@ def check_sharded_train_equivalence():
 
     loss1 = float(lm.loss_fn(params, cfg, batch)[0])
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_gspmd_mesh((2, 2), ("data", "model"))
     rules = make_rules(mesh)
     pspecs = logical_shardings(mesh, lm.model_meta(cfg), rules)
     params_sh = jax.tree_util.tree_map(jax.device_put, params, pspecs)
@@ -559,7 +559,7 @@ def check_dryrun_cell_tiny():
     from repro.parallel import make_rules, sanitized_shardings
     from repro.configs import get_smoke_config
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_gspmd_mesh((4, 2), ("data", "model"))
     rules = make_rules(mesh)
     for arch in ("granite_8b", "zamba2_7b", "xlstm_1_3b", "phi35_moe_42b"):
         cfg = get_smoke_config(arch)
@@ -585,7 +585,7 @@ def check_pipelined_lm_equivalence():
     batch = {"tokens": toks, "labels": toks}
     ref = float(lm.loss_fn(params, cfg, batch)[0])
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_gspmd_mesh((2, 2, 2), ("pod", "data", "model"))
     rules = make_rules(mesh, pipeline_pods=True)
     pspecs = pipeline_param_shardings(mesh, lm.model_meta(cfg), rules)
     params_sh = jax.tree_util.tree_map(jax.device_put, params, pspecs)
@@ -621,7 +621,7 @@ def check_serve_profile_equivalence():
     ref = float(lm.loss_fn(params, cfg, batch)[0])
 
     cfg_s = dataclasses.replace(cfg, reduce_dtype="bfloat16")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_gspmd_mesh((4, 2), ("data", "model"))
     rules = make_rules(mesh, profile="serve")
     pspecs = logical_shardings(mesh, lm.model_meta(cfg_s), rules)
     params_sh = jax.tree_util.tree_map(jax.device_put, params, pspecs)
@@ -635,7 +635,24 @@ def check_serve_profile_equivalence():
     print("PASS serve_profile_equivalence")
 
 
+def check_chip_smoke_four_chip_phases():
+    """chip_smoke.py's four-chip phases (slab, 2x2 pencil, factor1d; each
+    checked against numpy and for a spectrum sharded over every device) at
+    a tiny size on four of the fake devices, through eager front-end
+    calls on jax.make_mesh meshes."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+    devs = jax.devices()[:4]
+    slab = jax.make_mesh((4,), ("fft",), devices=devs)
+    pencil = jax.make_mesh((2, 2), ("px", "py"), devices=devs)
+    chip_smoke.four_chip_phases(slab, pencil, plan.Planner(), n2d=64,
+                                n3d=16, n1d=1 << 12)
+    print("PASS chip_smoke_four_chip_phases")
+
+
 if __name__ == "__main__":
+    check_chip_smoke_four_chip_phases()
     check_fft2_slab()
     check_fft3_pencil()
     check_rfft3_pencil()

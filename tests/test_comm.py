@@ -2,8 +2,8 @@
 
 Exchange *numerics* across real multi-device meshes live in
 tests/_dist_worker.py; here we cover spec resolution, the roofline
-planners, and the degenerate p=1 exchange (which also smoke-tests the
-jax.shard_map compat shim inside tier-1's fast path).
+planners, and the degenerate p=1 exchange (which also smoke-tests
+jax.shard_map inside tier-1's fast path).
 """
 
 import jax
@@ -12,7 +12,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core import comm
-from repro.core.compat import shard_map
 
 
 def test_get_backend_resolution():
@@ -89,9 +88,9 @@ def test_exchange_identity_on_one_device():
         def local(a, b, _bk=backend):
             return _bk.exchange((a, b), "ax", split=1, concat=0, p=1)
 
-        re, im = shard_map(local, mesh=mesh,
-                           in_specs=(P("ax", None), P("ax", None)),
-                           out_specs=(P(None, "ax"), P(None, "ax")))(*pair)
+        re, im = jax.shard_map(local, mesh=mesh,
+                               in_specs=(P("ax", None), P("ax", None)),
+                               out_specs=(P(None, "ax"), P(None, "ax")))(*pair)
         np.testing.assert_allclose(np.asarray(re), x)
         np.testing.assert_allclose(np.asarray(im), -x)
 
@@ -241,7 +240,7 @@ def test_gather_backends_agree_on_one_device():
         def local(a, b, _bk=backend):
             return _bk.gather((a, b), "ax")
 
-        outs[spec] = shard_map(
+        outs[spec] = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P("ax", None), P("ax", None)),
             out_specs=(P(None, "ax", None), P(None, "ax", None)))(q, s)

@@ -13,6 +13,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.mark.slow
 def test_distributed_suite():
     env = dict(os.environ)
+    # fake CPU devices for structure checks: the child must never take
+    # the accelerator, which the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     proc = subprocess.run(
