@@ -1,0 +1,11 @@
+"""Local 1D stages: device time per step of the DFT matmuls (ops in the
+matmul categories).  Mean over the chips."""
+
+from chipbench import xplane
+
+
+def read(trace, ctx):
+    per_dev = xplane.category_ns(trace, xplane.MATMUL)
+    if not any(per_dev.values()):
+        return None
+    return xplane.per_step_ms(trace, per_dev)
