@@ -32,6 +32,10 @@ import numpy as np
 
 Complex = Tuple[jax.Array, jax.Array]  # (re, im)
 
+#: named scope of the r2c pack and unpack (the inner c2c FFT stays outside
+#: it); the profiler's trace carries it in each op's ``op_name``
+R2C_SCOPE = "repro_r2c"
+
 # ---------------------------------------------------------------------------
 # complex-pair helpers
 # ---------------------------------------------------------------------------
@@ -272,34 +276,39 @@ def rfft(x: jax.Array, **kw) -> Complex:
     n = x.shape[-1]
     assert n % 2 == 0, "rfft requires even length"
     m = n // 2
-    z = (x[..., 0::2], x[..., 1::2])
+    with jax.named_scope(R2C_SCOPE):
+        z = (x[..., 0::2], x[..., 1::2])
     zf = fft(z, sign=-1, **kw)  # (..., m)
-    # Z[(-k) mod m], k = 0..m  (index m wraps to 0)
-    idx = (-np.arange(m + 1)) % m
-    zr = (zf[0][..., idx], zf[1][..., idx])
-    zk = (jnp.concatenate([zf[0], zf[0][..., :1]], -1),
-          jnp.concatenate([zf[1], zf[1][..., :1]], -1))
-    xe = cscale(cadd(zk, conj(zr)), 0.5)                       # even spectrum
-    xo_t = cadd(zk, cscale(conj(zr), -1.0))                    # Z - conj(Zrev)
-    xo = (0.5 * xo_t[1], -0.5 * xo_t[0])                       # /(2i)
-    w = _half_twiddle(n, -1)
-    return cadd(xe, cmul(w, xo))
+    with jax.named_scope(R2C_SCOPE):
+        # Z[(-k) mod m], k = 0..m  (index m wraps to 0)
+        idx = (-np.arange(m + 1)) % m
+        zr = (zf[0][..., idx], zf[1][..., idx])
+        zk = (jnp.concatenate([zf[0], zf[0][..., :1]], -1),
+              jnp.concatenate([zf[1], zf[1][..., :1]], -1))
+        xe = cscale(cadd(zk, conj(zr)), 0.5)                   # even spectrum
+        xo_t = cadd(zk, cscale(conj(zr), -1.0))                # Z - conj(Zrev)
+        xo = (0.5 * xo_t[1], -0.5 * xo_t[0])                   # /(2i)
+        w = _half_twiddle(n, -1)
+        return cadd(xe, cmul(w, xo))
 
 
 def irfft(x: Complex, **kw) -> jax.Array:
     """c2r inverse FFT; input (..., n//2+1), output real (..., n)."""
     m = x[0].shape[-1] - 1
     n = 2 * m
-    w = _half_twiddle(n, +1)
-    xr = (x[0][..., ::-1], x[1][..., ::-1])                    # X[m-k]
-    xe = cscale(cadd(x, conj(xr)), 0.5)
-    xo_f = cscale(cadd(x, cscale(conj(xr), -1.0)), 0.5)
-    xo = cmul(w, xo_f)                                          # undo half twiddle
-    # Z[k] = Xe[k] + i*Xo[k], k = 0..m-1
-    z = (xe[0][..., :m] - xo[1][..., :m], xe[1][..., :m] + xo[0][..., :m])
+    with jax.named_scope(R2C_SCOPE):
+        w = _half_twiddle(n, +1)
+        xr = (x[0][..., ::-1], x[1][..., ::-1])                # X[m-k]
+        xe = cscale(cadd(x, conj(xr)), 0.5)
+        xo_f = cscale(cadd(x, cscale(conj(xr), -1.0)), 0.5)
+        xo = cmul(w, xo_f)                              # undo half twiddle
+        # Z[k] = Xe[k] + i*Xo[k], k = 0..m-1
+        z = (xe[0][..., :m] - xo[1][..., :m],
+             xe[1][..., :m] + xo[0][..., :m])
     zi = ifft(z, **kw)
-    out = jnp.stack([zi[0], zi[1]], axis=-1)                    # interleave
-    return out.reshape(out.shape[:-2] + (n,))
+    with jax.named_scope(R2C_SCOPE):
+        out = jnp.stack([zi[0], zi[1]], axis=-1)                # interleave
+        return out.reshape(out.shape[:-2] + (n,))
 
 
 # ---------------------------------------------------------------------------

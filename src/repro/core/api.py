@@ -534,6 +534,7 @@ def _measure_finalists(scored, shape, kind, mesh, planner, build) -> NdPlan:
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.execute")
 def execute_nd(plan: NdPlan, x, mesh=None, planner: Optional[Planner] = None,
                chunks: int = 4, **layout_opts):
     """Run ``plan`` forward.  ``x``: real array for r2c, (re, im) pair for
@@ -556,6 +557,7 @@ def execute_nd(plan: NdPlan, x, mesh=None, planner: Optional[Planner] = None,
     return dfft.execute_pencil(plan, x, mesh, planner, chunks=chunks)
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.execute")
 def execute_nd_inverse(plan: NdPlan, c: Complex, mesh=None,
                        planner: Optional[Planner] = None, chunks: int = 4,
                        **layout_opts):
@@ -647,6 +649,7 @@ def _crop_spatial(y, plan: NdPlan, pair: bool):
     return y
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.fftn")
 def fftn(x, mesh=None, axes=None, planner: Optional[Planner] = None,
          comm="auto", mode: str = "estimate", ndim: Optional[int] = None,
          plan: Optional[NdPlan] = None, chunks: int = 4,
@@ -664,7 +667,7 @@ def fftn(x, mesh=None, axes=None, planner: Optional[Planner] = None,
             "fftn(x, ndim) is the old repro.core.algo.fftn signature; "
             "repro.core.fftn is now the planned front-end — pass ndim=... "
             "(or call repro.core.algo.fftn directly)",
-            DeprecationWarning, stacklevel=2)
+            DeprecationWarning, stacklevel=3)   # past the host span
         mesh, ndim = None, mesh
     c = _as_pair(x)
     d = _transform_ndim(c, ndim, plan)
@@ -675,6 +678,7 @@ def fftn(x, mesh=None, axes=None, planner: Optional[Planner] = None,
     return plan.crop_pair(out)
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.ifftn")
 def ifftn(x, mesh=None, axes=None, planner: Optional[Planner] = None,
           comm="auto", mode: str = "estimate", ndim: Optional[int] = None,
           plan: Optional[NdPlan] = None, chunks: int = 4,
@@ -695,6 +699,7 @@ def ifftn(x, mesh=None, axes=None, planner: Optional[Planner] = None,
     return _crop_spatial(y, plan, pair=True)
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.rfftn")
 def rfftn(x: jax.Array, mesh=None, axes=None,
           planner: Optional[Planner] = None, comm="auto",
           mode: str = "estimate", ndim: Optional[int] = None,
@@ -713,6 +718,7 @@ def rfftn(x: jax.Array, mesh=None, axes=None,
     return plan.crop_pair(out)
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.irfftn")
 def irfftn(x, shape: Optional[Sequence[int]] = None, mesh=None, axes=None,
            planner: Optional[Planner] = None, comm="auto",
            mode: str = "estimate", plan: Optional[NdPlan] = None,

@@ -103,8 +103,16 @@ def all_gather_pair(c: Complex, axis_name: str, axis: int = 0,
 # ---------------------------------------------------------------------------
 
 
+#: named scope of every exchange, its collectives and the packing around
+#: them (chunk slices, concatenations); the profiler's trace carries it in
+#: each op's ``op_name``
+EXCHANGE_SCOPE = "repro_exchange"
+
+
 class CommBackend:
-    """One redistribution strategy for pair-valued sharded exchanges."""
+    """One redistribution strategy for pair-valued sharded exchanges.
+    A strategy implements :meth:`_exchange`; :meth:`exchange` runs it
+    inside the exchange's named scope."""
 
     name: str = "abstract"
 
@@ -112,6 +120,12 @@ class CommBackend:
                  concat: int, p: int) -> Complex:
         """Redistribute: split ``split`` over the ``p`` participants of
         ``axis_name``, concatenate received blocks along ``concat``."""
+        with jax.named_scope(EXCHANGE_SCOPE):
+            return self._exchange(c, axis_name, split=split, concat=concat,
+                                  p=p)
+
+    def _exchange(self, c: Complex, axis_name: str, *, split: int,
+                  concat: int, p: int) -> Complex:
         raise NotImplementedError
 
     def gather(self, c: Complex, axis_name: str) -> Complex:
@@ -129,7 +143,7 @@ class CollectiveBackend(CommBackend):
 
     name = "collective"
 
-    def exchange(self, c, axis_name, *, split, concat, p):
+    def _exchange(self, c, axis_name, *, split, concat, p):
         return a2a_pair(c, axis_name, split, concat)
 
 
@@ -154,7 +168,7 @@ class PipelinedBackend(CommBackend):
     def __repr__(self) -> str:  # pragma: no cover - debugging aid
         return f"PipelinedBackend(chunks={self.chunks})"
 
-    def exchange(self, c, axis_name, *, split, concat, p):
+    def _exchange(self, c, axis_name, *, split, concat, p):
         shape = c[0].shape
         w = shape[split] // p
         chunks = max(1, min(self.chunks, w))
@@ -204,7 +218,7 @@ class AgasBackend(CommBackend):
 
     name = "agas"
 
-    def exchange(self, c, axis_name, *, split, concat, p):
+    def _exchange(self, c, axis_name, *, split, concat, p):
         re, im = all_gather_pair(c, axis_name, axis=concat, tiled=True)
         i = jax.lax.axis_index(axis_name)
         w = re.shape[split] // p

@@ -151,15 +151,19 @@ def rows_rfft(planner: Planner, x: jax.Array, n: int) -> Complex:
     the real signal cropped to the half spectrum."""
     if n % 2 == 0:
         return execute(planner.plan(n, kind="r2c"), x)
-    re, im = execute(planner.plan(n, kind="c2c"), (x, jnp.zeros_like(x)))
-    return re[..., : n // 2 + 1], im[..., : n // 2 + 1]
+    with jax.named_scope(algo.R2C_SCOPE):
+        z = (x, jnp.zeros_like(x))
+    re, im = execute(planner.plan(n, kind="c2c"), z)
+    with jax.named_scope(algo.R2C_SCOPE):
+        return re[..., : n // 2 + 1], im[..., : n // 2 + 1]
 
 
 def rows_irfft(planner: Planner, c: Complex, n: int) -> jax.Array:
     """c2r inverse of :func:`rows_rfft` (input ``(..., n//2+1)``)."""
     if n % 2 == 0:
         return execute(planner.plan(n, kind="c2r"), c)
-    full = hermitian_extend_last(c, n)
+    with jax.named_scope(algo.R2C_SCOPE):
+        full = hermitian_extend_last(c, n)
     return execute_inverse(planner.plan(n, kind="c2c"), full)[0]
 
 
