@@ -214,7 +214,7 @@ def main(argv=None) -> int:
         raise SystemExit(f"chip_smoke: --chips {args.chips} needs "
                          f"{args.chips} chips, JAX sees {len(devices)}")
     hw = hardware_for(dev.device_kind)
-    planner = Planner(hardware=hw, backends=("jnp",))
+    planner = Planner(hardware=hw)
     _check(planner.hw == hw, f"planner peaks {planner.hw} are not {hw}")
     cache = use_compile_cache()
     print(f"device: {dev.platform} {dev.device_kind!r} x{len(devices)}; "

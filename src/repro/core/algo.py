@@ -266,19 +266,20 @@ def _half_twiddle(n: int, sign: int) -> Complex:
     return jnp.asarray(np.cos(ang).astype(np.float32)), jnp.asarray(np.sin(ang).astype(np.float32))
 
 
-def rfft(x: jax.Array, **kw) -> Complex:
+def rfft(x: jax.Array, inner=None, **kw) -> Complex:
     """r2c FFT along the last axis. len must be even; output length n//2 + 1.
 
     Packs even/odd samples into a complex signal of length n/2, runs one c2c
     FFT, and unpacks with conjugate symmetry — halving MXU work exactly like
-    FFTW's real codelets halve flops.
+    FFTW's real codelets halve flops.  ``inner``: the c2c FFT of the packed
+    pair, if not :func:`fft` with ``kw``.
     """
     n = x.shape[-1]
     assert n % 2 == 0, "rfft requires even length"
     m = n // 2
     with jax.named_scope(R2C_SCOPE):
         z = (x[..., 0::2], x[..., 1::2])
-    zf = fft(z, sign=-1, **kw)  # (..., m)
+    zf = inner(z) if inner else fft(z, sign=-1, **kw)  # (..., m)
     with jax.named_scope(R2C_SCOPE):
         # Z[(-k) mod m], k = 0..m  (index m wraps to 0)
         idx = (-np.arange(m + 1)) % m
@@ -292,8 +293,10 @@ def rfft(x: jax.Array, **kw) -> Complex:
         return cadd(xe, cmul(w, xo))
 
 
-def irfft(x: Complex, **kw) -> jax.Array:
-    """c2r inverse FFT; input (..., n//2+1), output real (..., n)."""
+def irfft(x: Complex, inner=None, **kw) -> jax.Array:
+    """c2r inverse FFT; input (..., n//2+1), output real (..., n).
+    ``inner``: the scaled inverse c2c FFT of the packed pair, if not
+    :func:`ifft` with ``kw``."""
     m = x[0].shape[-1] - 1
     n = 2 * m
     with jax.named_scope(R2C_SCOPE):
@@ -305,7 +308,7 @@ def irfft(x: Complex, **kw) -> jax.Array:
         # Z[k] = Xe[k] + i*Xo[k], k = 0..m-1
         z = (xe[0][..., :m] - xo[1][..., :m],
              xe[1][..., :m] + xo[0][..., :m])
-    zi = ifft(z, **kw)
+    zi = inner(z) if inner else ifft(z, **kw)
     with jax.named_scope(R2C_SCOPE):
         out = jnp.stack([zi[0], zi[1]], axis=-1)                # interleave
         return out.reshape(out.shape[:-2] + (n,))

@@ -370,7 +370,7 @@ def plan_nd(shape: Sequence[int], kind: str = "c2c", mesh=None,
     assert kind in ("c2c", "r2c"), kind
     assert mode in ("estimate", "measured"), mode
     assert output_layout in OUTPUT_LAYOUTS, output_layout
-    planner = planner or Planner(backends=("jnp",))
+    planner = planner or Planner()
     sizes = _mesh_axis_sizes(mesh, axes)
     live = mesh is not None and not isinstance(mesh, dict)
 
@@ -545,7 +545,7 @@ def execute_nd(plan: NdPlan, x, mesh=None, planner: Optional[Planner] = None,
     2D-slab-only flags ``keep_transposed``/``permuted_cols`` the
     deprecated shims still pass.
     """
-    planner = planner or Planner(backends=("jnp",))
+    planner = planner or Planner()
     if plan.decomp == "local":
         return _execute_local(plan, x, planner)
     assert mesh is not None, "distributed plans need the live mesh"
@@ -564,7 +564,7 @@ def execute_nd_inverse(plan: NdPlan, c: Complex, mesh=None,
     """Run ``plan`` backward from the PADDED spectrum pair.  Returns a pair
     for c2c, a real array for r2c; sharded axes keep their divisibility
     padding (crop trailing axes to ``plan.shape``)."""
-    planner = planner or Planner(backends=("jnp",))
+    planner = planner or Planner()
     if plan.decomp == "local":
         return _execute_local_inverse(plan, c, planner)
     assert mesh is not None, "distributed plans need the live mesh"

@@ -26,6 +26,7 @@ matching FFTW semantics where a plan is tied to the FFT length.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional, Sequence, Tuple
 
@@ -105,9 +106,13 @@ class Plan:
         return 2.0 * muls * batch * n_eff * sum(self.factors)
 
     def bytes_moved(self, batch: int) -> float:
-        """HBM traffic estimate: each four-step stage reads+writes the array."""
+        """HBM traffic estimate: each four-step stage reads+writes the array;
+        the fused kernel keeps its stages in VMEM and reads+writes it once."""
         n_eff = self.n // 2 if self.kind in ("r2c", "c2r") else self.n
-        passes = max(len(self.factors), 1) + (0 if self.permuted else 1)
+        if self.backend.startswith("pallas"):
+            passes = 1
+        else:
+            passes = max(len(self.factors), 1) + (0 if self.permuted else 1)
         return 2.0 * passes * batch * n_eff * 8.0  # (re, im) f32
 
 
@@ -131,19 +136,28 @@ def _candidate_factorizations(n: int, max_base: int) -> Sequence[Tuple[int, ...]
     return sorted(cands)
 
 
+def default_backends() -> Tuple[str, ...]:
+    """The backends a Planner chooses among unless told: on a TPU the fused
+    Pallas kernel for the splits it runs and jnp for the rest; elsewhere
+    jnp alone (the kernel would only be interpreted)."""
+    return ("pallas", "jnp") if jax.default_backend() == "tpu" else ("jnp",)
+
+
 class Planner:
-    """Creates and caches plans. ``mode``: "estimate" | "measured"."""
+    """Creates and caches plans. ``mode``: "estimate" | "measured".
+    ``backends=None``: :func:`default_backends` of the platform JAX runs
+    on."""
 
     def __init__(self, hardware: HardwareSpec = TPU_V5E,
                  mode: str = "estimate", max_base: int = 128,
                  wisdom_path: Optional[str] = None,
-                 backends: Sequence[str] = ("jnp",),
+                 backends: Optional[Sequence[str]] = None,
                  wisdom: Optional[WisdomStore] = None):
         assert mode in ("estimate", "measured")
         self.hw = hardware
         self.mode = mode
         self.max_base = max_base
-        self.backends = tuple(backends)
+        self.backends = tuple(backends or default_backends())
         # a shared store may be passed in (e.g. one file for several
         # planners + the comm autotuner); otherwise open/create our own.
         self.wisdom = wisdom if wisdom is not None else WisdomStore(wisdom_path)
@@ -176,12 +190,18 @@ class Planner:
     # -- plan construction ---------------------------------------------------
 
     def _candidates(self, n: int, kind: str, permuted: bool):
+        from repro.kernels.dft_matmul.dft_matmul import kernel_factors
         n_eff = n // 2 if kind in ("r2c", "c2r") else n
         for backend in self.backends:
             if backend == "xla_native":
                 yield Plan(n, kind, (), backend)
                 continue
             for fac in _candidate_factorizations(n_eff, self.max_base):
+                if backend.startswith("pallas"):
+                    # only splits the kernel runs, in the order it runs them
+                    fac = kernel_factors(fac)
+                    if fac is None:
+                        continue
                 if permuted and len(fac) != 2:
                     continue
                 yield Plan(n, kind, fac, backend, permuted=permuted)
@@ -280,9 +300,25 @@ class Planner:
 # ---------------------------------------------------------------------------
 
 
-def execute(plan: Plan, x, **kw):
+def _fused(plan: Plan, sign: int = -1):
+    """The plan's c2c stage of length ``n_eff`` as the fused Pallas kernel
+    (unscaled)."""
+    from repro.kernels.dft_matmul import ops as dft_ops
+    return functools.partial(dft_ops.fft_four_step, factors=plan.factors,
+                             sign=sign, karatsuba=plan.karatsuba)
+
+
+def _fused_inverse(plan: Plan):
+    fused = _fused(plan, sign=+1)
+    n_eff = plan.factors[0] * plan.factors[1]
+    return lambda c: algo.cscale(fused(c), 1.0 / n_eff)
+
+
+def execute(plan: Plan, x):
     """Apply a plan along the last axis. c2c takes/returns an (re, im) pair;
-    r2c takes a real array and returns a pair; c2r the reverse."""
+    r2c takes a real array and returns a pair; c2r the reverse.  A
+    ``pallas`` plan runs its c2c stage (the inner one of r2c and c2r)
+    through the fused kernel; the r2c pack and unpack stay jnp."""
     if plan.backend == "xla_native":
         if plan.kind == "c2c":
             z = jnp.fft.fft(algo.to_complex(x))
@@ -293,12 +329,11 @@ def execute(plan: Plan, x, **kw):
         return jnp.fft.irfft(algo.to_complex(x)).astype(jnp.float32)
 
     if plan.backend.startswith("pallas"):
-        from repro.kernels.dft_matmul import ops as dft_ops
-        if plan.kind == "c2c" and len(plan.factors) == 2:
-            return dft_ops.fft_four_step(x, plan.factors, karatsuba=plan.karatsuba,
-                                         permuted=plan.permuted, **kw)
-        # pallas path only covers the 2-factor c2c hot loop; fall through for
-        # the r2c pack/unpack glue which is bandwidth-trivial.
+        if plan.kind == "c2c":
+            return _fused(plan)(x, permuted=plan.permuted)
+        if plan.kind == "r2c":
+            return algo.rfft(x, inner=_fused(plan))
+        return algo.irfft(x, inner=_fused_inverse(plan))
 
     opts = dict(factors=plan.factors or None, karatsuba=plan.karatsuba)
     if plan.kind == "c2c":
@@ -311,7 +346,10 @@ def execute(plan: Plan, x, **kw):
 
 
 def execute_inverse(plan: Plan, x):
-    """Inverse transform matching ``plan`` (c2c only)."""
+    """Inverse transform matching ``plan`` (c2c only).  A ``pallas`` plan
+    runs the fused kernel with the conjugate tables, unless it is
+    permuted: that inverse consumes the permuted order in jnp
+    (:func:`repro.core.algo.ifft_from_permuted`)."""
     assert plan.kind == "c2c"
     if plan.backend == "xla_native":
         z = jnp.fft.ifft(algo.to_complex(x))
@@ -319,4 +357,6 @@ def execute_inverse(plan: Plan, x):
     if plan.permuted:
         return algo.ifft_from_permuted(x, factors=plan.factors,
                                        karatsuba=plan.karatsuba)
+    if plan.backend.startswith("pallas"):
+        return _fused_inverse(plan)(x)
     return algo.ifft(x, factors=plan.factors or None, karatsuba=plan.karatsuba)

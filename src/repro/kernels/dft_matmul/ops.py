@@ -14,10 +14,10 @@ import jax
 from .dft_matmul import fft_four_step_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("factors", "karatsuba", "permuted",
-                                             "block_rows"))
+@functools.partial(jax.jit, static_argnames=("factors", "sign", "karatsuba",
+                                             "permuted"))
 def fft_four_step(x: Tuple[jax.Array, jax.Array], factors: Tuple[int, int],
-                  *, karatsuba: bool = False, permuted: bool = False,
-                  block_rows: int = 8) -> Tuple[jax.Array, jax.Array]:
-    return fft_four_step_pallas(x, tuple(factors), karatsuba=karatsuba,
-                                permuted=permuted, block_rows=block_rows)
+                  *, sign: int = -1, karatsuba: bool = False,
+                  permuted: bool = False) -> Tuple[jax.Array, jax.Array]:
+    return fft_four_step_pallas(x, tuple(factors), sign=sign,
+                                karatsuba=karatsuba, permuted=permuted)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.dft_matmul import fft_four_step, fft_four_step_ref
+from repro.kernels.dft_matmul.dft_matmul import block_rows
 from repro.kernels.transpose import transpose, transpose_ref
 from repro.kernels.twiddle import complex_multiply, complex_multiply_ref
 
@@ -42,12 +43,17 @@ def test_dft_matmul_modes(karatsuba, permuted):
                                atol=1e-4 * scale)
 
 
-@pytest.mark.parametrize("block_rows", [1, 3, 8])
-def test_dft_matmul_block_rows(block_rows):
-    x = _pair((6, 256))
-    k = fft_four_step(x, (16, 16), block_rows=block_rows)
-    r = fft_four_step_ref(x, (16, 16))
+@pytest.mark.parametrize("rows", [1, 3, 8, 17])
+def test_dft_matmul_block_rows(rows):
+    """The tile's rows come from n (8 at 16384); a batch they do not
+    divide ends in a partial tile."""
+    assert block_rows(128 * 128) == 8
+    x = _pair((rows, 128 * 128))
+    k = fft_four_step(x, (128, 128))
+    r = fft_four_step_ref(x, (128, 128))
+    assert k[0].shape == x[0].shape
     np.testing.assert_allclose(np.asarray(k[0]), np.asarray(r[0]), atol=1e-3)
+    np.testing.assert_allclose(np.asarray(k[1]), np.asarray(r[1]), atol=1e-3)
 
 
 def test_dft_matmul_batched_nd():

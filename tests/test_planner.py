@@ -42,12 +42,13 @@ def test_measured_planning_runs_and_caches(tmp_path):
 
 
 def test_execute_matches_backend_choices():
+    # 8192 = (64, 128): a length the fused kernel's planner plans
     rng = np.random.default_rng(0)
-    x = rng.standard_normal((4, 1024)).astype(np.float32)
+    x = rng.standard_normal((4, 8192)).astype(np.float32)
     ref = np.fft.fft(x)
     for backend in ("jnp", "jnp_karatsuba", "xla_native", "pallas"):
         p = plan.Planner(mode="estimate", backends=(backend,))
-        pl = p.plan(1024, "c2c")
+        pl = p.plan(8192, "c2c")
         out = plan.execute(pl, algo.to_pair(x.astype(np.complex64)))
         z = np.asarray(out[0]) + 1j * np.asarray(out[1])
         if pl.permuted:
@@ -139,8 +140,10 @@ def test_wisdom_export_import_byte_identical(tmp_path):
 @pytest.mark.parametrize("kind", ["c2c", "r2c"])
 def test_plan_roundtrip_matrix(backend, kind):
     """execute/execute_inverse (or the r2c/c2r plan pair) round-trips for
-    every kind x backend a Plan can hold."""
-    n = 256
+    every kind x backend a Plan can hold (the fused kernel's plans at a
+    length with a split it runs: 16384 = (128, 128), r2c's inner 8192 =
+    (64, 128))."""
+    n = 16384 if backend.startswith("pallas") else 256
     rng = np.random.default_rng(7)
     p = plan.Planner(mode="estimate", backends=(backend,))
     if kind == "c2c":
